@@ -100,18 +100,12 @@ def scorer_bench(reps: int = None, brute_reps: int = None) -> dict:
     return row
 
 
-def scale_bench(reps: int = None) -> dict:
-    """Placement scoring at the 1000-service / 100-host point: one batched
-    ``PlacementProblem`` dispatch over the capped candidate set (hot-host
-    movers x cool-host targets), sharded over available devices, with
-    sharded-vs-unsharded byte parity."""
-    import jax
-
-    from repro.core.solver import PlacementProblem
-
+def scale_snapshot():
+    """The 1000-service / 100-host placement snapshot: the capped candidate
+    set (every host's stay-put row plus hot-host movers x cool-host
+    targets) — returns (problem, subsets, capacities, models, rps, x0)."""
     from .e6_scalability import _solve_fleet
 
-    reps = SCALE_REPS if reps is None else reps
     problem, host_of, caps, models, rps, x0 = _solve_fleet((SCALE_FLEET,))
     residents = {h: [] for h in caps}
     for i, s in enumerate(problem.specs):
@@ -127,12 +121,26 @@ def scale_bench(reps: int = None) -> dict:
             for t in targets:
                 subsets.append(sorted(residents[t] + [i]))
                 caps_list.append(caps[t])
+    return problem, subsets, caps_list, models, rps, x0
+
+
+def scale_bench(reps: int = None) -> dict:
+    """Placement scoring at the 1000-service / 100-host point: one batched
+    ``PlacementProblem`` dispatch over the capped candidate set (hot-host
+    movers x cool-host targets), sharded over available devices, with
+    sharded-vs-unsharded byte parity."""
+    import jax
+
+    from repro.core.solver import PlacementProblem
+
+    reps = SCALE_REPS if reps is None else reps
+    problem, subsets, caps_list, models, rps, x0 = scale_snapshot()
     pp_s = PlacementProblem(problem, subsets, caps_list, shard="auto")
     pp_0 = PlacementProblem(problem, subsets, caps_list, shard=False)
     s_s = pp_s.scores(models, rps, x0)
     s_0 = pp_0.scores(models, rps, x0)
     return {
-        "services": len(problem.specs), "hosts": len(caps),
+        "services": len(problem.specs), "hosts": SCALE_FLEET[0],
         "candidates": pp_s.n_candidates,
         "buckets": [list(bk.key) for bk in pp_s.buckets],
         "batched_us": common.bench(

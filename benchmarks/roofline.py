@@ -2,8 +2,7 @@
 
 Reads benchmarks/artifacts/dryrun/*.json (produced by repro.launch.dryrun),
 prints the per-(arch x shape x mesh) three-term roofline and writes the
-markdown table + the LM-service calibration file used by the autoscaling
-demo (closing the loop: the surfaces RASK optimizes come from compiled HLO).
+markdown table.
 """
 import json
 from pathlib import Path
@@ -55,24 +54,6 @@ def markdown_table(data):
             f"| {r['model_flops']:.2e} | {r['useful_flops_frac']:.3f} "
             f"| {frac:.4f} | {kfrac:.4f} |")
     return "\n".join(lines)
-
-
-def lm_calibration(data):
-    """tokens/s/chip per arch from the decode_32k single-pod roofline
-    (kernel floor — the deployable path uses the Pallas decode kernel)."""
-    cal = {}
-    for r in data:
-        if r["shape"] != "decode_32k" or r["mesh"] != "pod16x16":
-            continue
-        dom = max(r["compute_s"], kernel_floor_s(r), r["collective_s"])
-        if dom <= 0:
-            continue
-        # decode_32k: 128 sequences produce 1 token per step
-        tokens_per_s_per_chip = 128 / (dom * 256)
-        # rung scaling mirrors profiles._RUNG_FRACTION (N_eff linear in rung)
-        cal[r["arch"]] = {str(rung): tokens_per_s_per_chip * 4.0 / rung
-                          for rung in (1, 2, 3, 4)}
-    return cal
 
 
 def rask_objective_rows(s_list=(3, 9, 27), k_starts=8):
@@ -221,8 +202,6 @@ def main():
         return
     table = markdown_table(data)
     (ART / "roofline_table.md").write_text(table)
-    cal = lm_calibration(data)
-    (ART / "lm_calibration.json").write_text(json.dumps(cal, indent=1))
     for r in data:
         dom = max(r["compute_s"], r["memory_s"], r["collective_s"])
         print(f"roofline[{r['arch']},{r['shape']},{r['mesh']}],"

@@ -127,7 +127,7 @@ def check_e6() -> int:
     |S| scaling exponent of the bucketed solve must stay <= 1.2 with the
     1000-service / 100-host point inside one 10 s control interval, the
     sharded solve must be byte-identical to the unsharded one (exactly
-    0.0), and the pipelined decide must hide >= 50% of the synchronous
+    0.0, which holds on XLA-CPU devices), and the pipelined decide must hide >= 50% of the synchronous
     solve latency behind the apply + scrape window."""
     from . import common, e6_scalability
 
@@ -528,4 +528,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

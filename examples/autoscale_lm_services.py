@@ -3,8 +3,7 @@ co-located LM *serving* services sharing one pod's chip budget.
 
 Elasticity dimensions per service: chips (resource), context budget
 (data-quality analog), model rung (model-size analog). Throughput surfaces
-are calibrated from the dry-run roofline if benchmarks/artifacts/
-lm_calibration.json exists (run `python -m benchmarks.roofline` first).
+are the analytic roofline rates of ``repro.env.profiles.lm_profile``.
 
     PYTHONPATH=src python examples/autoscale_lm_services.py
 """

@@ -18,8 +18,8 @@ Single-dispatch fused decide (the default: ``fused=True, backend="pgd"``)
 The whole post-exploration cycle — the batched ridge fit over padded design
 matrices, the multi-start projected-gradient solve, the exact capacity
 projection and the Gaussian NOISE — is composed into ONE jitted on-device
-pipeline: the stacked models never leave the device, the padded
-design-matrix buffers are donated to the compiled program, and a single
+pipeline: the stacked models never leave the device, the streaming fit's
+device-resident state is donated to the compiled program, and a single
 host transfer at the end extracts [cached optimum | noised plan | scores].
 On a multi-host ``Fleet`` the same pipeline solves every host's subproblem
 against its OWN capacity in one vmapped dispatch (``FleetSolverProblem``),
@@ -251,18 +251,13 @@ class _AotFn:
     def export_roundtrip(self, *args):
         """``jax.export`` round-trip of the underlying program: serialize,
         deserialize, return the rehydrated callable — proof the compiled
-        decide survives a process boundary (AOT artifact caching).  Returns
-        None where the running jax lacks export support; callers keep the
-        in-process AOT path."""
-        try:
-            from jax import export as jax_export
-            avals = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(tuple(a.shape),
-                                               np.dtype(a.dtype)), args)
-            exp = jax_export.export(jax.jit(self._jit.__wrapped__))(*avals)
-            return jax_export.deserialize(exp.serialize()).call
-        except Exception:
-            return None
+        decide survives a process boundary (AOT artifact caching)."""
+        from jax import export as jax_export
+        avals = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(tuple(a.shape), np.dtype(a.dtype)),
+            args)
+        exp = jax_export.export(jax.jit(self._jit.__wrapped__))(*avals)
+        return jax_export.deserialize(exp.serialize()).call
 
     def __call__(self, *args):
         if self._compiled is None or self._sig != self._sig_of(args):
@@ -1226,17 +1221,14 @@ class RASKAgent(PlanningAgent):
                 return (tail(stacked(w), x0, key, rps_eff, eta, (pred,)),
                         w, state, fw, fstate)
 
-        # donate the design-matrix/delta buffers — and in streaming mode
-        # the accumulator states, which the program updates in place and
-        # returns (CPU XLA cannot donate and would warn on every compile,
-        # so donation is accelerator-only).  The prior/gate arrays are NOT
-        # donated: the zero-prior pair is cached host-side and re-sent.
-        if jax.default_backend() == "cpu":
+        # donate the streaming accumulator states, which the program
+        # updates in place and returns; nothing else has an output of its
+        # shape to reuse its buffer (the packed design/delta buffers, the
+        # priors and gate arrays), so donating them would only warn
+        if k_cap is None:
             donate: Tuple[int, ...] = ()
-        elif k_cap is None:
-            donate = (0,) if fc is None else (0, 3)
         else:
-            donate = (0, 1) if fc is None else (0, 1, 4, 5)
+            donate = (0,) if fc is None else (0, 4)
         if cfg.aot:
             return _AotFn(core, donate)
         return jax.jit(core, donate_argnums=donate)

@@ -65,6 +65,10 @@ import numpy as np
 #     delta path (the O(new rows) uploads that REPLACE the full windows).
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
+# float32 products at full precision: on TPU the default f32 dot is one bf16
+# pass, which would round the Gram systems the ridge solves consume
+_hdot = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
 
 def polynomial_exponents(n_features: int, degree: int) -> np.ndarray:
     """All exponent tuples with 0 <= sum(e) <= degree, bias term first.
@@ -93,12 +97,12 @@ def _expand(x, exponents: np.ndarray):
 def _fit(Xs, Y, degree: int, n_features: int, ridge):
     exps = polynomial_exponents(n_features, degree)
     Phi = _expand(Xs, exps)                                   # (N, T)
-    A = Phi.T @ Phi
+    A = _hdot(Phi.T, Phi)
     # scale-aware ridge: constant feature columns (frozen elasticity dims)
     # make A singular; regularize relative to its trace
     lam = ridge * (1.0 + jnp.trace(A) / A.shape[0])
     A = A + lam * jnp.eye(Phi.shape[1], dtype=Phi.dtype)
-    b = Phi.T @ Y
+    b = _hdot(Phi.T, Y)
     return jnp.linalg.solve(A, b)
 
 
@@ -117,7 +121,7 @@ class PolynomialModel:
     def predict(self, x):
         """Estimate the target for raw (unscaled) feature vector(s) x (..., F)."""
         xs = jnp.asarray(x, jnp.float32) / jnp.asarray(self.x_scale, jnp.float32)
-        return _expand(xs, self.exponents) @ self.w
+        return _hdot(_expand(xs, self.exponents), self.w)
 
     # pytree protocol: only w is a leaf so models can ride through jit/vmap.
     def tree_flatten(self):
@@ -274,12 +278,12 @@ def fit_batched_arrays(Xp, Yp, row_mask, exponents, term_mask, n_terms,
     def one(X, Y, rm, e, tm, nt, xs, wp, pl):
         Phi = _expand_gather(X / xs, e, max_degree) * tm[None, :]
         Phi = Phi * rm[:, None]
-        A = Phi.T @ Phi
+        A = _hdot(Phi.T, Phi)
         # same scale-aware ridge as ``_fit``; the divisor is the relation's
         # *active* term count so padded shapes reproduce the unpadded lambda
         lam = ridge * (1.0 + jnp.trace(A) / nt)
         A = A + (lam + pl) * jnp.eye(Phi.shape[1], dtype=Phi.dtype)
-        return jnp.linalg.solve(A, Phi.T @ (Y * rm) + pl * (wp * tm))
+        return jnp.linalg.solve(A, _hdot(Phi.T, Y * rm) + pl * (wp * tm))
 
     return jax.vmap(one)(Xp, Yp, row_mask, exponents, term_mask,
                          n_terms.astype(jnp.float32), x_scale,
@@ -395,8 +399,8 @@ class BatchedFitPlan:
         """Overwrite the reusable padded host buffers with ``data`` (one
         (X (N_r, F_r), Y (N_r,)) pair per relation, in plan order; the
         newest ``row_capacity`` rows win if N_r exceeds it) and return
-        (Xp, Yp, row_mask) views — the fused decide uploads these once and
-        donates the device buffers to the compiled pipeline."""
+        (Xp, Yp, row_mask) views — the fused decide uploads these once per
+        cycle."""
         TRACE_COUNTS["h2d_design_upload"] += 1    # runtime transfer counter
         self._Xp[:] = 0.0
         self._Yp[:] = 0.0
@@ -535,8 +539,8 @@ class BatchedFitPlan:
             take = jnp.clip(slot, 0, cap - 1)
             phi_old = phi_r[take] * evict[:, None]
             y_old = y_r[take] * evict
-            G = G + phi_new.T @ phi_new - phi_old.T @ phi_old
-            b = b + phi_new.T @ y_new - phi_old.T @ y_old
+            G = G + _hdot(phi_new.T, phi_new) - _hdot(phi_old.T, phi_old)
+            b = b + _hdot(phi_new.T, y_new) - _hdot(phi_old.T, y_old)
             phi_r = phi_r.at[slot].set(phi_new, mode="drop")
             y_r = y_r.at[slot].set(y_new, mode="drop")
             return phi_r, y_r, G, b, count + jnp.sum(dm).astype(jnp.int32)
@@ -560,7 +564,7 @@ class BatchedFitPlan:
             valid = (jnp.arange(cap) < jnp.minimum(count, cap)
                      ).astype(phi_r.dtype)
             pm = phi_r * valid[:, None]
-            return pm.T @ pm, pm.T @ (y_r * valid)
+            return _hdot(pm.T, pm), _hdot(pm.T, y_r * valid)
 
         gram, xty = jax.vmap(one)(state.phi, state.y, state.count)
         return StreamState(state.phi, state.y, gram, xty, state.count)

@@ -18,15 +18,13 @@ Paper surfaces are chosen to reproduce the qualitative structure of Fig. 6:
    parallelization") — nearly flat in cores.
 
 LM surfaces are roofline-derived: tokens/s/chip from the bf16 compute bound
-vs the HBM weight-streaming bound of the (possibly down-rung'd) model, with
-an optional calibration dict produced by the dry-run cost analysis
-(benchmarks/roofline.py) overriding the analytic rates.
+vs the HBM weight-streaming bound of the (possibly down-rung'd) model.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Dict, Mapping, Optional, Sequence
+from typing import Callable, Dict, Mapping, Sequence
 
 from ..core.elasticity import ApiDescription, ElasticityParameter
 from ..core.slo import SLO
@@ -148,23 +146,13 @@ def _lm_rate_tokens_per_chip(n_params: float, rung: float,
 
 def lm_profile(name: str, n_params: float, *, default_rps: float = 4.0,
                max_chips: float = 16.0, out_tokens: float = 256.0,
-               context_slo: float = 8192.0, rung_slo: float = 3.0,
-               calibration: Optional[Mapping[int, float]] = None
+               context_slo: float = 8192.0, rung_slo: float = 3.0
                ) -> ServiceProfile:
-    """Profile for one LM service (arch ``name`` with ``n_params`` weights).
-
-    calibration: optional {rung: tokens/s/chip} measured by the dry-run
-    roofline harness; overrides the analytic rate.
-    """
+    """Profile for one LM service (arch ``name`` with ``n_params`` weights)."""
 
     def tp(p: Mapping[str, float]) -> float:
         rung = min(max(p["rung"], 1.0), 4.0)
-        if calibration:
-            lo, hi = int(math.floor(rung)), int(math.ceil(rung))
-            rate = calibration[lo] + (rung - lo) * (calibration[hi] -
-                                                    calibration[lo])
-        else:
-            rate = _lm_rate_tokens_per_chip(n_params, rung)
+        rate = _lm_rate_tokens_per_chip(n_params, rung)
         # request cost in decode-token equivalents: generated tokens plus the
         # prefill of `context` tokens (compute-bound, ~20x cheaper per token)
         req_cost = out_tokens + 0.05 * p["context"]

@@ -30,7 +30,6 @@ scorer-driven evacuation, capacity degradation, service arrival/departure
 from __future__ import annotations
 
 import argparse
-import json
 from pathlib import Path
 
 import numpy as np
@@ -42,18 +41,36 @@ from ..env.profiles import ServiceProfile
 
 
 def lm_services(max_chips: float = 16.0):
-    cal_path = Path(__file__).resolve().parents[3] / "benchmarks" / \
-        "artifacts" / "lm_calibration.json"
-    cal = json.loads(cal_path.read_text()) if cal_path.exists() else {}
-    profiles = []
-    for name, rps in [("gemma3-1b", 12.0), ("qwen2-moe-a2.7b", 6.0),
-                      ("mamba2-370m", 20.0)]:
-        n = ARCHS[name].n_params_active()
-        profiles.append(lm_profile(
-            name, n, default_rps=rps, max_chips=max_chips,
-            calibration={int(k): v for k, v in cal.get(name, {}).items()}
-            or None))
-    return profiles
+    return [lm_profile(name, ARCHS[name].n_params_active(), default_rps=rps,
+                       max_chips=max_chips)
+            for name, rps in [("gemma3-1b", 12.0), ("qwen2-moe-a2.7b", 6.0),
+                              ("mamba2-370m", 20.0)]]
+
+
+def lm_environment(duration_s: float, *, chips: float = 16.0,
+                   pattern: str = "diurnal", seed: int = 0,
+                   replicas: int = 1, hosts: int = 1, host_caps=None):
+    """The seeded LM-service environment ``main`` drives: returns (env,
+    knowledge, profiles). ``host_caps`` (per-device chip budgets) builds a
+    heterogeneous fleet and overrides ``chips``/``hosts``."""
+    profiles = lm_services(sum(host_caps) if host_caps else chips)
+    pat = diurnal if pattern == "diurnal" else bursty
+    patterns = {p.type: pat(p.default_rps * 2.5, duration_s=duration_s,
+                            seed=seed + i)
+                for i, p in enumerate(profiles)}
+    if host_caps:
+        # heterogeneous fleet: every device its own budget, services placed
+        # proportionally to it (the bucketed per-host solver's home turf)
+        devices = [(f"edge-{i}", {"chips": c})
+                   for i, c in enumerate(host_caps)]
+        env = EdgeEnvironment(profiles, patterns=patterns, seed=seed,
+                              replicas=replicas, hosts=devices,
+                              placement="capacity")
+    else:
+        env = EdgeEnvironment(profiles, {"chips": chips / max(hosts, 1)},
+                              patterns=patterns, seed=seed,
+                              replicas=replicas, hosts=hosts)
+    return env, {p.type: dict(p.knowledge) for p in profiles}, profiles
 
 
 def main(argv=None):
@@ -124,30 +141,12 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    if args.host_caps:
-        caps = [float(c) for c in args.host_caps.split(",")]
-        total_chips = sum(caps)
-    else:
-        total_chips = args.chips
-    profiles = lm_services(total_chips)
+    caps = [float(c) for c in args.host_caps.split(",")] \
+        if args.host_caps else None
     duration = args.minutes * 60.0
-    pat = diurnal if args.pattern == "diurnal" else bursty
-    patterns = {p.type: pat(p.default_rps * 2.5, duration_s=duration,
-                            seed=args.seed + i)
-                for i, p in enumerate(profiles)}
-    if args.host_caps:
-        # heterogeneous fleet: every device its own budget, services placed
-        # proportionally to it (the bucketed per-host solver's home turf)
-        hosts = [(f"edge-{i}", {"chips": c}) for i, c in enumerate(caps)]
-        env = EdgeEnvironment(profiles, patterns=patterns, seed=args.seed,
-                              replicas=args.replicas, hosts=hosts,
-                              placement="capacity")
-    else:
-        per_host_chips = args.chips / max(args.hosts, 1)
-        env = EdgeEnvironment(profiles, {"chips": per_host_chips},
-                              patterns=patterns, seed=args.seed,
-                              replicas=args.replicas, hosts=args.hosts)
-    knowledge = {p.type: dict(p.knowledge) for p in profiles}
+    env, knowledge, profiles = lm_environment(
+        duration, chips=args.chips, pattern=args.pattern, seed=args.seed,
+        replicas=args.replicas, hosts=args.hosts, host_caps=caps)
     shard = "auto" if args.shard == "auto" else (
         False if args.shard.lower() in ("off", "false", "0")
         else int(args.shard))
@@ -220,4 +219,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
