@@ -210,7 +210,10 @@ class DecisionInfo:
     # itself runs on device during the next control interval), ``collect_s``
     # the block_until_ready + transfer of the PREVIOUS cycle's solve;
     # ``runtime_s`` is their sum — the decide latency the control loop
-    # actually blocks on, with the solve hidden behind apply + scrape
+    # actually blocks on, with the solve hidden behind apply + scrape.
+    # The synchronous fused decide sets them too, at the bounds of its
+    # ``repro.rask.dispatch`` and ``repro.rask.collect`` spans: the enqueue
+    # of its program, and the host blocked on it plus the transfer
     pipelined: bool = False
     dispatch_s: float = 0.0
     collect_s: float = 0.0

@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace
 # CycleResult is re-exported here for seed-era callers (it moved to api.py)
 from .api import CycleResult, DecisionInfo, PlanningAgent, ScalingPlan
 from .forecast import LoadForecaster
@@ -444,29 +445,38 @@ class RASKAgent(PlanningAgent):
 
         All services are read with one bulk telemetry query (one lock/scan
         instead of |S|)."""
-        states = {}
-        windowed = self.platform.window_states(since=t - window, until=t)
-        for sid in self.services:
-            state = windowed.get(sid)
-            if not state:
-                continue
-            row = dict(state)
-            row.update(self.platform.assignment(sid))  # features = applied params
-            self.table.append(sid, row)
-            states[sid] = row
-            rps = row.get("rps")
-            if rps is not None and np.isfinite(rps):
-                self._last_rps[sid] = float(rps)
-                self._rps_scale[sid] = max(self._rps_scale.get(sid, 0.0),
-                                           float(rps))
-        if self.accountant is not None:
-            self.burn_states = self.accountant.update(t)
+        with trace.span(trace.RASK_OBSERVE) as span:
+            states = {}
+            windowed = self.platform.window_states(since=t - window, until=t)
+            for sid in self.services:
+                state = windowed.get(sid)
+                if not state:
+                    continue
+                row = dict(state)
+                # features = applied params
+                row.update(self.platform.assignment(sid))
+                self.table.append(sid, row)
+                states[sid] = row
+                rps = row.get("rps")
+                if rps is not None and np.isfinite(rps):
+                    self._last_rps[sid] = float(rps)
+                    self._rps_scale[sid] = max(self._rps_scale.get(sid, 0.0),
+                                               float(rps))
+            if self.accountant is not None:
+                self.burn_states = self.accountant.update(t)
+            span.set_metadata(rows=len(states))
         return states
 
     # -- Algorithm 1 ------------------------------------------------------------
     def decide(self, obs: Mapping[str, Mapping[str, float]]) -> ScalingPlan:
         """One RASK round: explore or fit+solve; returns the proposed plan
         (the caller — environment or ``cycle`` — applies it)."""
+        with trace.span(trace.RASK_DECIDE) as span:
+            plan = self._decide(obs)
+            span.set_metadata(explored=int(self.last_decision.explored))
+        return plan
+
+    def _decide(self, obs) -> ScalingPlan:
         self.rounds += 1
         if self.rounds < self.cfg.xi:                       # lines 3-5
             self.last_decision = DecisionInfo(explored=True)
@@ -511,6 +521,7 @@ class RASKAgent(PlanningAgent):
         else:
             runtime, compile_s = time.perf_counter() - t0, 0.0
         a, noised, score = out
+        dispatch_s, collect_s = self._phase_s
         used_starts, used_iters = self._budget_starts, self._budget_iters
         self._cached_x = np.asarray(a, np.float32)          # §IV-B3 cache
         prev_score, self._last_score = self._last_score, float(score)
@@ -525,7 +536,7 @@ class RASKAgent(PlanningAgent):
             score_starts=self._score_starts if scored else 0,
             score_iters=self._score_iters if scored else 0,
             burn_alerts=len(alerts), max_burn=self._max_burn(),
-            **self._fc_stats())
+            dispatch_s=dispatch_s, collect_s=collect_s, **self._fc_stats())
         return self._plan(noised)
 
     def _decide_pipelined(self, obs, moves, scored: bool,
@@ -551,9 +562,10 @@ class RASKAgent(PlanningAgent):
         pend, self._pending = self._pending, None
         collected = None
         if pend is not None and pend["gen"] == self._topo_gen:
-            jax.block_until_ready((pend["out"], pend["w"]))
-            out = np.asarray(pend["out"])   # the cycle's ONE transfer
-            self.stacked = pend["plan"].stacked(pend["w"])
+            with trace.span(trace.RASK_COLLECT):
+                jax.block_until_ready((pend["out"], pend["w"]))
+                out = np.asarray(pend["out"])   # the cycle's ONE transfer
+                self.stacked = pend["plan"].stacked(pend["w"])
             self._models_view = None
             a, noised, score, pred = self._split_out(
                 out, pend["dim"], pend.get("n_fc", 0))
@@ -573,13 +585,16 @@ class RASKAgent(PlanningAgent):
         # -- phase 2: fit + async-dispatch the next solve ---------------------
         dispatch_s = compile_s = 0.0
         used_starts = used_iters = 0
-        prep = self._prepare_fit()
+        with trace.span(trace.RASK_PACK) as span:
+            prep = self._prepare_fit()
+            if prep is not None:
+                span.set_metadata(rows=self._prep_rows(prep))
+                seed = int(self.rng.integers(2 ** 31))
+                x0 = self._x0()
         if prep is None:
             if collected is None:
                 self.stacked = None       # models incomplete: keep exploring
         else:
-            seed = int(self.rng.integers(2 ** 31))
-            x0 = self._x0()
             fkey = self._fused_key(self._prep_k_cap(prep), self._fc_k_cap())
             cold = self._prep_cold(prep) or \
                 not (fkey in self._warm_keys and fkey in self._fused_fns)
@@ -720,15 +735,19 @@ class RASKAgent(PlanningAgent):
         ``_last_solve_cold`` when the pass compiled a new jitted variant;
         re-invoking within the same ``decide`` reuses ``_cycle_draws`` so
         the re-run is byte-identical and the rng stream advances once."""
+        self._phase_s = (0.0, 0.0)
         if self.cfg.fused and self.cfg.backend == "pgd":
-            prep = self._prepare_fit()                      # lines 6-9
+            with trace.span(trace.RASK_PACK) as span:
+                prep = self._prepare_fit()                  # lines 6-9
+                if prep is not None:
+                    span.set_metadata(rows=self._prep_rows(prep))
+                    if self._cycle_draws is None:
+                        self._cycle_draws = (int(self.rng.integers(2 ** 31)),
+                                             self._x0())
             if prep is None:
                 self.stacked = None
                 self._last_solve_cold = False
                 return None
-            if self._cycle_draws is None:
-                self._cycle_draws = (int(self.rng.integers(2 ** 31)),
-                                     self._x0())
             seed, x0 = self._cycle_draws
             # cold = this pipeline variant will compile (never called, OR
             # called before but since evicted from the bounded fn cache) —
@@ -1032,92 +1051,116 @@ class RASKAgent(PlanningAgent):
 
     def _dispatch_fused(self, prep, obs, seed: int, x0: np.ndarray):
         """Dispatch one fused decide (async — device futures out): returns
-        (out, w, fused key, n_fc) where n_fc is the number of per-service
-        predictions in ``out`` (0 without the forecaster).  Streaming preps
-        rebuild or rank-k push the device-resident accumulators —
-        structural AND forecaster — as a side effect; the state pytrees are
-        donated to (and returned by) the compiled program."""
+        (out, w, seconds the dispatch took, n_fc) where n_fc is the number
+        of per-service predictions in ``out`` (0 without the forecaster).
+        Streaming preps rebuild or rank-k push the device-resident
+        accumulators — structural AND forecaster — as a side effect; the
+        state pytrees are donated to (and returned by) the compiled
+        program."""
         if not (isinstance(prep, tuple) and len(prep) == 2
                 and prep[0] in ("batch", "delta")):
             prep = ("batch", prep)        # raw fit data (legacy call sites)
         plan = self._fit_plan
         kind, payload = prep
-        k_cap = self._prep_k_cap(prep)
-        fk_cap = self._fc_k_cap()
-        fkey = self._fused_key(k_cap, fk_cap)
-        rps_np = self._rps_vector(obs)
+        streaming = self._streaming()
         fc = self._forecast \
             if (self._forecast_on() and self._fc_prep is not None) else None
-        fc_args: tuple = ()
-        n_fc = 0
-        if fc is not None:
-            # score the prediction that targeted THIS round, then build the
-            # cycle's traced gate inputs: lag windows, use mask, AR priors
-            fc.settle(self.rounds, rps_np)
-            lagm = fc.lag_matrix(self.table)
-            fwp, fpl = fc.prior_arrays()
-            fc_args = (jnp.asarray(fwp), jnp.asarray(fpl),
-                       jnp.asarray(lagm), jnp.asarray(fc.use_mask()))
-            n_fc = len(fc.services)
-        wp, pl = self._prior_args()
-        priors = (jnp.asarray(wp), jnp.asarray(pl))
-        tail = (jnp.asarray(x0, jnp.float32), jax.random.PRNGKey(seed),
-                jnp.asarray(rps_np), jnp.float32(self._eta_t()))
-        if self._streaming():
-            if kind == "batch":
-                # invalidated (first fit, churn, plan change): rebuild the
-                # device window, then run the steady-state program empty
-                self._stream = self._stream_rebuild(payload)
-                payload = [(_EMPTY_X, _EMPTY_Y)] * plan.n_relations
-            st = self._stream
-            dbuf = plan.fill_delta(payload, k_cap)
-            fn = self._fused_fn(fkey, k_cap, fk_cap)
-            if fc is None:
-                out, w, state = fn(st["state"], jnp.asarray(dbuf), *priors,
-                                   *tail)
+        with trace.span(trace.RASK_PACK, rows=self._prep_rows(prep)):
+            k_cap = self._prep_k_cap(prep)
+            fk_cap = self._fc_k_cap()
+            fkey = self._fused_key(k_cap, fk_cap)
+            cold = not (fkey in self._warm_keys and fkey in self._fused_fns)
+            rps_np = self._rps_vector(obs)
+            fc_args: tuple = ()
+            n_fc = 0
+            if fc is not None:
+                # score the prediction that targeted THIS round, then build
+                # the cycle's traced gate inputs: lag windows, use mask, AR
+                # priors
+                fc.settle(self.rounds, rps_np)
+                lagm = fc.lag_matrix(self.table)
+                fwp, fpl = fc.prior_arrays()
+                fc_args = (jnp.asarray(fwp), jnp.asarray(fpl),
+                           jnp.asarray(lagm), jnp.asarray(fc.use_mask()))
+                n_fc = len(fc.services)
+            wp, pl = self._prior_args()
+            priors = (jnp.asarray(wp), jnp.asarray(pl))
+            tail = (jnp.asarray(x0, jnp.float32), jax.random.PRNGKey(seed),
+                    jnp.asarray(rps_np), jnp.float32(self._eta_t()))
+            if streaming:
+                if kind == "batch":
+                    # invalidated (first fit, churn, plan change): rebuild
+                    # the device window, then run the steady-state program
+                    # empty
+                    self._stream = self._stream_rebuild(payload)
+                    payload = [(_EMPTY_X, _EMPTY_Y)] * plan.n_relations
+                st = self._stream
+                fn = self._fused_fn(fkey, k_cap, fk_cap)
+                args = (st["state"], jnp.asarray(plan.fill_delta(payload,
+                                                                 k_cap)),
+                        *priors)
+                if fc is not None:
+                    fkind, fpairs = self._fc_prep
+                    if fkind == "batch" or fc.state is None:
+                        # forecaster ring invalidated too: rebuild it on
+                        # device, then run the same steady-state program
+                        # empty
+                        fc.state = fc.plan.stream_rebuild(fpairs)
+                        fpairs = [(_EMPTY_X, _EMPTY_Y)] * fc.plan.n_relations
+                    args += (fc.state,
+                             jnp.asarray(fc.plan.fill_delta(fpairs, fk_cap)),
+                             *fc_args)
             else:
-                fkind, fpairs = self._fc_prep
-                if fkind == "batch" or fc.state is None:
-                    # forecaster ring invalidated too: rebuild it on device,
-                    # then run the same steady-state program empty
-                    fc.state = fc.plan.stream_rebuild(fpairs)
-                    fpairs = [(_EMPTY_X, _EMPTY_Y)] * fc.plan.n_relations
-                fdbuf = fc.plan.fill_delta(fpairs, fk_cap)
-                out, w, state, fw, fstate = fn(
-                    st["state"], jnp.asarray(dbuf), *priors,
-                    fc.state, jnp.asarray(fdbuf), *fc_args, *tail)
-                fc.state, fc.last_w = fstate, fw
+                fn = self._fused_fn(fkey, None, None)
+                args = (jnp.asarray(plan.fill_packed(payload)), *priors)
+                if fc is not None:
+                    args += (jnp.asarray(fc.plan.fill_packed(
+                        self._fc_prep[1])), *fc_args)
+        with trace.span(trace.RASK_DISPATCH, cold=int(cold)):
+            t0 = time.perf_counter()
+            res = fn(*args, *tail)
+            dispatch_s = time.perf_counter() - t0
+        if streaming:
+            if fc is None:
+                out, w, state = res
+            else:
+                out, w, state, fc.last_w, fc.state = res
             st["state"] = state
             st["pushes"] += 1
             every = self.cfg.stream_resync_every
             if every and st["pushes"] % every == 0:
                 # exact Gram recompute from the device ring (no upload):
                 # bounds incremental float32 drift on arbitrarily long runs
-                st["state"] = plan.stream_resync(st["state"])
-                if fc is not None and fc.state is not None:
-                    fc.state = fc.plan.stream_resync(fc.state)
+                with trace.span(trace.RASK_RESYNC):
+                    st["state"] = plan.stream_resync(st["state"])
+                    if fc is not None and fc.state is not None:
+                        fc.state = fc.plan.stream_resync(fc.state)
+        elif fc is None:
+            out, w = res
         else:
-            buf = plan.fill_packed(payload)
-            fn = self._fused_fn(fkey, None, None)
-            if fc is None:
-                out, w = fn(jnp.asarray(buf), *priors, *tail)
-            else:
-                fbuf = fc.plan.fill_packed(self._fc_prep[1])
-                out, w, fw = fn(jnp.asarray(buf), *priors,
-                                jnp.asarray(fbuf), *fc_args, *tail)
-                fc.last_w = fw
+            out, w, fc.last_w = res
         self._warm_keys.add(fkey)  # compiled now — future decides are warm
         self._warm_keys &= set(self._fused_fns)   # evicted keys re-cool
-        return out, w, fkey, n_fc
+        return out, w, dispatch_s, n_fc
+
+    @staticmethod
+    def _prep_rows(prep) -> int:
+        """Training rows a ``(kind, pairs)`` fit prep carries (a delta's new
+        rows, or a batch's whole window)."""
+        return sum(len(y) for _, y in prep[1])
 
     def _decide_fused(self, prep, obs, seed: int, x0: np.ndarray
                       ) -> Tuple[np.ndarray, np.ndarray, float]:
         """Fit (+ forecast) + solve + project + NOISE as ONE compiled
         dispatch; returns (optimum for the warm-start cache, noised plan
-        vector, score)."""
-        out, w, _, n_fc = self._dispatch_fused(prep, obs, seed, x0)
-        out = np.asarray(out)     # the cycle's ONE device->host transfer
-        self.stacked = self._fit_plan.stacked(w)   # weights stay on device
+        vector, score). Sets ``_phase_s``: the seconds of the dispatch and
+        of the collect (host blocked on the device, then the transfer)."""
+        out, w, dispatch_s, n_fc = self._dispatch_fused(prep, obs, seed, x0)
+        with trace.span(trace.RASK_COLLECT):
+            t0 = time.perf_counter()
+            out = np.asarray(out)  # the cycle's ONE device->host transfer
+            self.stacked = self._fit_plan.stacked(w)  # weights stay on device
+            self._phase_s = (dispatch_s, time.perf_counter() - t0)
         self._models_view = None
         a, noised, score, pred = self._split_out(out, self.problem.dim, n_fc)
         if pred is not None:

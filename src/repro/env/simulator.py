@@ -46,6 +46,7 @@ from ..core.elasticity import ServiceId
 from ..core.fleet import Fleet
 from ..core.platform import MUDAP
 from ..core.slo import global_fulfillment, service_fulfillment
+from ..obs import trace
 from .profiles import ServiceProfile
 from .workloads import Pattern, constant
 
@@ -602,7 +603,9 @@ class EdgeEnvironment:
         if isinstance(agent, Agent):
             obs = agent.observe(self.t)
             plan = agent.decide(obs)
-            receipt = self.platform.apply_plan(plan)
+            with trace.span(trace.MUDAP_APPLY) as span:
+                receipt = self.platform.apply_plan(plan)
+                span.set_metadata(changed=sum(o.ok for o in receipt.outcomes))
             info = getattr(agent, "last_decision", None) or DecisionInfo()
             return CycleResult(getattr(agent, "rounds", -1), info.explored,
                                receipt.applied(), info.runtime_s, info.score,
@@ -626,18 +629,22 @@ class EdgeEnvironment:
         self._routes = None
         for step in range(1, steps + 1):
             self.t += 1.0
-            while pending and pending[0].t <= self.t:
-                self.apply_event(pending.pop(0), agent)
-            if self._routes is None:
-                self._routes = [(b.i, self.patterns[k])
-                                for k, b in self.services.items()]
-            for j, pat in self._routes:          # workloads are opaque callables
-                self.pool.rps[j] = pat(self.t)
-            self.pool.tick(self.t)               # whole fleet, one batched step
-            self.platform.scrape(self.t)
+            with trace.span(trace.ENV_TICK, t=self.t):
+                while pending and pending[0].t <= self.t:
+                    self.apply_event(pending.pop(0), agent)
+                if self._routes is None:
+                    self._routes = [(b.i, self.patterns[k])
+                                    for k, b in self.services.items()]
+                for j, pat in self._routes:      # workloads are opaque callables
+                    self.pool.rps[j] = pat(self.t)
+                self.pool.tick(self.t)           # whole fleet, one batched step
+                self.platform.scrape(self.t)
             if step % int(cycle_s) == 0:
-                result = self._drive(agent)
-                fulfillment, per_service = self.measured_fulfillment()
+                with trace.span(trace.ENV_DRIVE) as span:
+                    result = self._drive(agent)
+                    span.set_metadata(round=getattr(agent, "rounds", -1))
+                with trace.span(trace.ENV_RECORD):
+                    fulfillment, per_service = self.measured_fulfillment()
                 info = getattr(agent, "last_decision", None)
                 accountant = getattr(agent, "accountant", None)
                 fleet_burn = accountant.global_state() \

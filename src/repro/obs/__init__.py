@@ -6,6 +6,8 @@ The control plane's production face: ``SLOAccountant`` turns raw
 multiwindow multiburn alerts that ``RASKAgent`` consumes as a first-class
 scaling signal; ``MetricRegistry`` + ``golden_signals`` + ``render`` expose
 the same state (plus solver internals from ``DecisionInfo``) to scrapes.
+``trace`` names the program's host spans, which a profiler trace records on
+the device's clock.
 """
 from .slo_accounting import (
     FAST_BURN,

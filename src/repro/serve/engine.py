@@ -33,7 +33,10 @@ seeded run both engines must produce identical token streams.
 Everything is synchronous and deterministic so tests can drive it tick by
 tick, mirroring the 1 s cycle of the stream-processing services in the
 paper. The stacked step's wall-clock (``last_step_s`` / ``step_ewma_s``) is
-the *measured* latency that feeds the autoscaler's telemetry.
+the *measured* latency that feeds the autoscaler's telemetry. Under a
+profiler trace, each step, admission and decode dispatch is a host span
+(``repro.obs.trace``); an admission's span carries its queue wait, from
+``submit`` to its prefill's dispatch.
 """
 from __future__ import annotations
 
@@ -47,6 +50,7 @@ import numpy as np
 
 from ..core.regression import TRACE_COUNTS
 from ..models import Model
+from ..obs import trace
 
 MIN_BUCKET = 8          # smallest prefill compile bucket (tokens)
 EWMA_ALPHA = 0.25       # step-latency smoothing for telemetry
@@ -67,6 +71,7 @@ class Request:
     max_new_tokens: int = 16
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    submitted_at: float = 0.0             # engine clock at submit
 
 
 @dataclasses.dataclass
@@ -95,7 +100,6 @@ class _EngineBase:
         self.last_step_s = 0.0                   # measured decode wall-clock
         self.step_ewma_s: Optional[float] = None
         self.last_prefill_s = 0.0
-        self.prefill_ewma_s: Optional[float] = None
 
     # -- elasticity API (what MUDAP's ScalingAPI calls) -----------------------
     def apply(self, param: str, value: float) -> None:
@@ -120,13 +124,17 @@ class _EngineBase:
 
     # -- request flow ---------------------------------------------------------
     def submit(self, req: Request) -> None:
+        req.submitted_at = time.perf_counter()
         self.queue.append(req)
+
+    def _kept(self, req: Request) -> int:
+        """How many prompt tokens an admission keeps."""
+        return min(len(req.prompt), self.cfg.context, self.cfg.max_seq)
 
     def _truncate(self, req: Request) -> np.ndarray:
         """Keep the newest ``context`` prompt tokens (and never more than the
         cache can hold)."""
-        keep = min(len(req.prompt), self.cfg.context, self.cfg.max_seq)
-        return req.prompt[-keep:]
+        return req.prompt[-self._kept(req):]
 
     def _observe_step(self, dt: float) -> None:
         self.last_step_s = dt
@@ -135,8 +143,6 @@ class _EngineBase:
 
     def _observe_prefill(self, dt: float) -> None:
         self.last_prefill_s = dt
-        self.prefill_ewma_s = dt if self.prefill_ewma_s is None else \
-            (1.0 - EWMA_ALPHA) * self.prefill_ewma_s + EWMA_ALPHA * dt
 
 
 class ServingEngine(_EngineBase):
@@ -191,21 +197,26 @@ class ServingEngine(_EngineBase):
             if slot in self.active or not self.queue:
                 continue
             req = self.queue[0]
-            prompt = self._truncate(req)
-            n = len(prompt)
-            if n > budget:
+            if self._kept(req) > budget:
                 continue                  # not enough budget this step
-            self.queue.pop(0)
-            budget -= n
-            width = bucket_length(n, self.cfg.max_seq) if self._buckets else n
-            toks = np.zeros((1, width), np.int32)
-            toks[0, :n] = prompt
-            t0 = time.perf_counter()
-            first, self._cache, self._last = self._admit_one(
-                self.params, self._cache, self._last, jnp.asarray(toks),
-                jnp.int32(n), jnp.int32(slot))
-            first = int(first)            # host sync: end of the dispatch
-            self._observe_prefill(time.perf_counter() - t0)
+            with trace.span(trace.SERVE_ADMIT, rid=req.rid,
+                            slot=slot) as span:
+                self.queue.pop(0)
+                prompt = self._truncate(req)
+                n = len(prompt)
+                budget -= n
+                width = bucket_length(n, self.cfg.max_seq) \
+                    if self._buckets else n
+                toks = np.zeros((1, width), np.int32)
+                toks[0, :n] = prompt
+                t0 = time.perf_counter()
+                span.set_metadata(length=n, bucket=width, wait_us=int(
+                    1e6 * (t0 - req.submitted_at)))
+                first, self._cache, self._last = self._admit_one(
+                    self.params, self._cache, self._last, jnp.asarray(toks),
+                    jnp.int32(n), jnp.int32(slot))
+                first = int(first)        # host sync: end of the dispatch
+                self._observe_prefill(time.perf_counter() - t0)
             req.generated.append(first)
             self.active[slot] = req
             self.prompt_tokens_in += n
@@ -214,25 +225,29 @@ class ServingEngine(_EngineBase):
         """One engine tick: admit, then ONE decode dispatch for the whole
         slot pool. Returns tokens produced (for *active* slots — idle lanes
         free-run and their output is discarded)."""
-        self._admit()
-        t0 = time.perf_counter()
-        nxt, self._cache = self._step(self.params, self._cache, self._last)
-        self._last = nxt
-        toks = np.asarray(nxt)            # the step's one device->host sync
-        self._observe_step(time.perf_counter() - t0)
-        produced = 0
-        finished = []
-        for slot, req in list(self.active.items()):
-            req.generated.append(int(toks[slot]))
-            produced += 1
-            if len(req.generated) >= req.max_new_tokens:
-                req.done = True
-                finished.append(slot)
-                self.completed.append(req)
-        for slot in finished:
-            del self.active[slot]
-        self.steps += 1
-        self.tokens_out += produced
+        with trace.span(trace.SERVE_STEP, active=len(self.active),
+                        queued=len(self.queue)):
+            self._admit()
+            with trace.span(trace.SERVE_DECODE, active=len(self.active)):
+                t0 = time.perf_counter()
+                nxt, self._cache = self._step(self.params, self._cache,
+                                              self._last)
+                self._last = nxt
+                toks = np.asarray(nxt)    # the step's one device->host sync
+                self._observe_step(time.perf_counter() - t0)
+            produced = 0
+            finished = []
+            for slot, req in list(self.active.items()):
+                req.generated.append(int(toks[slot]))
+                produced += 1
+                if len(req.generated) >= req.max_new_tokens:
+                    req.done = True
+                    finished.append(slot)
+                    self.completed.append(req)
+            for slot in finished:
+                del self.active[slot]
+            self.steps += 1
+            self.tokens_out += produced
         return produced
 
 

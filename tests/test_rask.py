@@ -121,6 +121,17 @@ def test_compile_time_reported_separately():
     agent.decide(obs)
     assert agent.last_decision.runtime_s > 0.0
     assert agent.last_decision.compile_s == 0.0
+    # the synchronous fused decide also splits a warm cycle into the
+    # dispatch of its program and the collect of its result
+    env, agent, hist = run_rask(backend="pgd", duration=200, xi=15)
+    solved = [h for h in hist if not h.explored]
+    assert solved[0].compile_s > solved[0].runtime_s
+    agent.decide(agent.observe(env.t))
+    info = agent.last_decision
+    assert not info.explored and not info.pipelined
+    assert info.compile_s == 0.0
+    assert info.dispatch_s > 0.0 and info.collect_s > 0.0
+    assert info.dispatch_s + info.collect_s <= info.runtime_s
 
 
 # -- online solver budget adaptation (ISSUE 5 satellite) ----------------------
