@@ -1,13 +1,12 @@
 """Pallas TPU decode attention — one new token against a long KV cache.
 
-Decode is memory-bound: the kernel's job is to stream the (S, KH, D) cache
-through VMEM exactly once at full HBM bandwidth while the tiny (KH, G, D)
-query tile stays resident. Grid: (B, ns) with the sequence-block axis
-innermost; each step loads one (bs, KH, D) block holding every kv head (the
-block's last two dims are the array's own, which is what the TPU tiling
-rules accept), and an unrolled loop over the KH heads runs the online
-softmax of that head's G query heads. The acc/m/l scratch carries across
-blocks, exactly like flash attention.
+Decode is memory-bound: the kernel's job is to stream the head-major
+(KH, S, D) cache through VMEM exactly once at full HBM bandwidth while the
+tiny (KH, G, D) query tile stays resident. Grid: (B, ns) with the
+sequence-block axis innermost; each step loads one (KH, bs, D) block
+holding every kv head, and an unrolled loop over the KH heads runs the
+online softmax of that head's G query heads on its (bs, D) rows. The
+acc/m/l scratch carries across blocks, exactly like flash attention.
 
 ``length``/``start`` arrive as one (B, 2) i32 operand in SMEM (traced —
 they change every step; recompiling per position would be absurd). Its
@@ -48,8 +47,8 @@ def _da_kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     mask = (pos < length) & (pos >= start)             # (1, bs)
     for h in range(n_kv):
         q = q_ref[0, h]                                # (G, D)
-        k = k_ref[0, :, h, :]                          # (bs, D)
-        v = v_ref[0, :, h, :]
+        k = k_ref[0, h]                                # (bs, D)
+        v = v_ref[0, h]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask, s, NEG_INF)                # (G, bs)
@@ -73,12 +72,12 @@ def _da_kernel(bounds_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention_pallas(q, k_cache, v_cache, length, start=0, *,
                             block_s: int = 512, interpret: bool = False):
-    """q: (B, H, D); caches: (B, S, KH, D); attend to slots [start, length).
+    """q: (B, H, D); caches: (B, KH, S, D); attend to slots [start, length).
 
     Returns (B, H, D).
     """
     B, H, D = q.shape
-    S, KH = k_cache.shape[1], k_cache.shape[2]
+    KH, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KH
     bs = min(block_s, S)
     assert S % bs == 0, (S, bs)
@@ -97,8 +96,8 @@ def decode_attention_pallas(q, k_cache, v_cache, length, start=0, *,
             pl.BlockSpec((B, 2), lambda b, isb: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, KH, G, D), lambda b, isb: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, KH, D), lambda b, isb: (b, isb, 0, 0)),
-            pl.BlockSpec((1, bs, KH, D), lambda b, isb: (b, isb, 0, 0)),
+            pl.BlockSpec((1, KH, bs, D), lambda b, isb: (b, 0, isb, 0)),
+            pl.BlockSpec((1, KH, bs, D), lambda b, isb: (b, 0, isb, 0)),
         ],
         out_specs=pl.BlockSpec((1, KH, G, D), lambda b, isb: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),

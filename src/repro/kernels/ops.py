@@ -79,7 +79,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 @partial(jax.jit, static_argnames=("impl", "interpret"))
 def decode_attention(q, k_cache, v_cache, length, start=0, *,
                      impl: str = "pallas", interpret: bool = False):
-    """q: (B,H,D) one new token; caches: (B,S,KH,D); attend to [start, length)."""
+    """q: (B,H,D) one new token; caches: (B,KH,S,D); attend to [start, length)."""
     if impl == "reference":
         return ref.decode_attention_reference(q, k_cache, v_cache, length,
                                               start=start)

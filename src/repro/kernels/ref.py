@@ -39,22 +39,22 @@ def flash_attention_reference(q, k, v, *, causal: bool = True,
 # -- decode attention (one new token vs long KV) -----------------------------------
 
 def decode_attention_reference(q, k_cache, v_cache, length, start=0):
-    """q: (B,H,D); caches: (B,S,KH,D); attend to cache slots [start, length).
+    """q: (B,H,D); caches: (B,KH,S,D); attend to cache slots [start, length).
 
     Returns (B,H,D). ``length``/``start`` may be traced scalars (local
     windows pass start = length - window).
     """
     B, H, D = q.shape
-    S, KH = k_cache.shape[1], k_cache.shape[2]
+    KH, S = k_cache.shape[1], k_cache.shape[2]
     G = H // KH
     qg = q.reshape(B, KH, G, D)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache).astype(jnp.float32)
+    scores = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache).astype(jnp.float32)
     scores = scores * (D ** -0.5)
     pos = jnp.arange(S)[None, :]
     mask = (pos < length) & (pos >= start)
     scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v_cache)
     return out.reshape(B, H, D)
 
 

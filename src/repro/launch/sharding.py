@@ -147,7 +147,9 @@ def batch_shardings(batch_shapes, mesh, dim: int = 0):
 
 
 def cache_shardings(cache_shapes, mesh):
-    """KV caches: (L, B, S, KH, D) -> batch over data, sequence over model.
+    """KV caches -> batch over data, sequence over model: the decode caches
+    are head-major (L, B, KH, S, D), the cross-attention caches (L, B, S,
+    KH, D).
 
     SSM states (L, B, ...): batch over data. Scalars replicated.
     """
@@ -164,8 +166,9 @@ def cache_shardings(cache_shapes, mesh):
                                    "k_local", "v_local"):
             # batch over data + sequence over model; batch=1 (long_500k)
             # falls back to pure context sharding
-            cands = [P(None, b, "model"), P(None, None, "model"),
-                     P(None, b), P()]
+            heads = () if names[-1].startswith("cross") else (None,)
+            cands = [P(None, b, *heads, "model"),
+                     P(None, None, *heads, "model"), P(None, b), P()]
         elif len(shape) >= 2:
             cands = [P(None, b), P()]
         else:

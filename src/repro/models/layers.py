@@ -124,6 +124,29 @@ def _attend(q, k, v, mask):
     return out
 
 
+def _attend_cached(q, ck, cv, k, v, here, mask):
+    """Decode core over a cache that is read and never written.
+
+    q: (B,1,KH,G,D); ck, cv: (B,KH,T,D); k, v: (B,KH,1,D), the token's fresh
+    row, which belongs at the cache position ``here`` (T,) marks; mask:
+    (B,1,T) bool, that position included. The fresh row's score takes that
+    position's place, so the softmax runs over the same scores in the same
+    order as ``_attend`` over the cache with the row written in.
+    """
+    scale = q.shape[-1] ** -0.5
+    scores = jnp.einsum("bskgd,bktd->bkgst", q, ck).astype(jnp.float32) * scale
+    fresh = jnp.einsum("bskgd,bktd->bkgst", q, k).astype(jnp.float32) * scale
+    scores = jnp.where(here, fresh, scores)
+    scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    p_fresh = jnp.sum(jnp.where(here, probs, 0), axis=-1, keepdims=True)
+    out = jnp.einsum("bkgst,bktd->bskgd", jnp.where(here, 0, probs), cv,
+                     preferred_element_type=jnp.float32) \
+        + jnp.einsum("bkgst,bktd->bskgd", p_fresh, v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(v.dtype)
+
+
 def _flash(q, k, v, *, causal, window, interpret):
     from ..kernels import ops as kops
     B, S, KH, G, D = q.shape
@@ -180,10 +203,18 @@ def attention(p: Params, x, cfg: ModelConfig, *, positions, kv_x=None,
     window: None = unbounded; a *static int* enables the Pallas flash path;
     in the decode path it may also be a traced scalar (gemma3's per-layer
     local/global interleave rides through one scan).
-    cache: (k_cache, v_cache) each (B, S_max, KH, D); cache_pos: scalar write
-    index for decode. cache_length overrides the #valid slots (ring caches
-    write at pos %% W but stay fully valid once warm). Returns
-    (out, new_cache_kv or (k, v) just computed).
+
+    Decode (one token): cache is (k_cache, v_cache), each head-major
+    (B, KH, S_max, D), so that a head's rows lie together as the dot reads
+    them, and cache_pos the position the token's row belongs at. The cache
+    is read, never written: the token attends over the cached rows in
+    [start, cache_pos) together with its own fresh k/v, cast to the cache
+    dtype. The caller writes that row at cache_pos after its layer loop.
+    cache_length overrides the #valid slots (ring caches write at pos %% W
+    but stay fully valid once warm).
+
+    Returns (out, (k, v)): the k/v this call computed, (B, S, KH, D); in
+    decode the one new row, (B, KH, 1, D) in the cache dtype.
     """
     B, S, _ = x.shape
     h, kh, dh, g = cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.kv_groups
@@ -202,29 +233,30 @@ def attention(p: Params, x, cfg: ModelConfig, *, positions, kv_x=None,
                  cfg.rope_theta).transpose(0, 2, 1, 3)
 
     if cache is not None and cache_pos is not None:
-        # decode: append the (single) new kv at cache_pos, attend to prefix
         ck, cv = cache
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cache_pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cache_pos, 0, 0))
-        T = ck.shape[1]
+        k = k.transpose(0, 2, 1, 3).astype(ck.dtype)
+        v = v.transpose(0, 2, 1, 3).astype(cv.dtype)
+        T = ck.shape[2]
         length = cache_pos + 1 if cache_length is None else cache_length
         start = jnp.int32(0) if window is None \
             else jnp.maximum(jnp.int32(0), length - window)
         if cfg.attn_impl.startswith("pallas") and S == 1:
+            # the kernel reads the new row from the cache: write it into a
+            # copy of this layer's slice
             from ..kernels import ops as kops
+            ck = jax.lax.dynamic_update_slice(ck, k, (0, 0, cache_pos, 0))
+            cv = jax.lax.dynamic_update_slice(cv, v, (0, 0, cache_pos, 0))
             qd = q.reshape(B, kh * g, dh)
             out = kops.decode_attention(
                 qd, ck, cv, length, start=start,
                 interpret=cfg.attn_impl == "pallas_interpret")
             out = out.reshape(B, S, kh, g, dh)
         else:
-            kpos = jnp.arange(T)[None, :]
+            kpos = jnp.arange(T)
             m = (kpos < length) & (kpos >= start)
-            m = jnp.broadcast_to(m[:, None, :], (B, S, T))
-            out = _attend(q, ck, cv, m)
-        new_cache = (ck, cv)
+            m = jnp.broadcast_to(m[None, None, :], (B, S, T))
+            out = _attend_cached(q, ck, cv, k, v, kpos == cache_pos, m)
+        new_cache = (k, v)
     else:
         T = src.shape[1]
         use_flash = (cfg.attn_impl.startswith("pallas") and kv_x is None

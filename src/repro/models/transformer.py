@@ -153,9 +153,19 @@ def decoder_forward(params: Params, cfg: ModelConfig, tokens,
     return logits, aux, kvs
 
 
+def _head_major(kv):
+    """(L,B,S,KH,D) k or v from a forward pass -> the caches' (L,B,KH,S,D)."""
+    return kv.transpose(0, 1, 3, 2, 4)
+
+
+def _pad_seq(kv, pad: int):
+    """Pad an (L,B,KH,S,D) cache leaf with ``pad`` empty positions."""
+    return jnp.pad(kv, ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0)))
+
+
 def decoder_init_cache(cfg: ModelConfig, batch: int, max_seq: int):
     dtype = jnp.dtype(cfg.dtype)
-    kv = jnp.zeros((cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.d_head),
+    kv = jnp.zeros((cfg.n_layers, batch, cfg.n_kv_heads, max_seq, cfg.d_head),
                    dtype)
     return {"k": kv, "v": kv, "pos": jnp.int32(0)}
 
@@ -173,10 +183,7 @@ def decoder_prefill(params: Params, cfg: ModelConfig, tokens, max_seq: int,
     """
     B, S = tokens.shape
     logits, _, kvs = decoder_forward(params, cfg, tokens, want_cache=True)
-    k, v = kvs                                       # (L,B,S,KH,D)
-    pad = max_seq - S
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    k, v = (_pad_seq(_head_major(kv), max_seq - S) for kv in kvs)
     if length is None:
         last, pos = logits[:, -1], jnp.int32(S)
     else:
@@ -187,8 +194,30 @@ def decoder_prefill(params: Params, cfg: ModelConfig, tokens, max_seq: int,
     return last, cache
 
 
+def _layer(cache_leaf, layer):
+    """One layer's slice of a stacked cache, read where it lies. The loop
+    body indexes the cache rather than scanning it as ``xs``: a scan would
+    hand each layer a copy, and under the serving engine's vmap move the
+    slot axis behind the layer axis (a transpose of the whole cache)."""
+    return jax.lax.dynamic_index_in_dim(cache_leaf, layer, 0, keepdims=False)
+
+
+def _write_rows(cache_leaf, rows, pos):
+    """Write the new (L,B,KH,1,D) rows at sequence position ``pos`` of an
+    (L,B,KH,Smax,D) cache: a decode step's one write to it, in place when
+    the cache is donated (a scatter under a vmap over per-slot ``pos``)."""
+    return jax.lax.dynamic_update_slice(cache_leaf, rows, (0, 0, 0, pos, 0))
+
+
 def decoder_decode(params: Params, cfg: ModelConfig, tokens, cache):
-    """One decode step. tokens: (B,1); cache holds (L,B,Smax,KH,D)."""
+    """One decode step. tokens: (B,1); cache holds head-major
+    (L,B,KH,Smax,D) K and V and the scalar write cursor ``pos``.
+
+    The layer loop reads each layer's cache slice in place and never
+    modifies it: attention takes the cached rows before ``pos`` together
+    with the token's own fresh k/v. The loop returns only the new
+    (L,B,KH,1,D) rows, and one write after it puts them at ``pos``.
+    """
     B = tokens.shape[0]
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
     pos = cache["pos"]
@@ -197,18 +226,20 @@ def decoder_decode(params: Params, cfg: ModelConfig, tokens, cache):
 
     def body(carry, xs):
         x, aux = carry
-        lp, window, ck, cv = xs
-        x, a, (nk, nv) = _decoder_block(cfg, lp, x, positions, window,
-                                        cache_kv=(ck, cv), cache_pos=pos)
-        return (x, aux + a), (nk, nv)
+        lp, window, layer = xs
+        kv = (_layer(cache["k"], layer), _layer(cache["v"], layer))
+        x, a, rows = _decoder_block(cfg, lp, x, positions, window,
+                                    cache_kv=kv, cache_pos=pos)
+        return (x, aux + a), rows
 
     (x, _), (nks, nvs) = jax.lax.scan(
         body, (x, jnp.float32(0.0)),
-        (params["layers"], windows, cache["k"], cache["v"]))
+        (params["layers"], windows, jnp.arange(cfg.n_layers)))
     x = norm(params["final_norm"], x, cfg.norm)
     head = params.get("head")
     logits = x @ (head if head is not None else params["embed"].T.astype(x.dtype))
-    new_cache = {"k": nks, "v": nvs, "pos": pos + 1}
+    new_cache = {"k": _write_rows(cache["k"], nks, pos),
+                 "v": _write_rows(cache["v"], nvs, pos), "pos": pos + 1}
     return logits[:, -1], new_cache
 
 
@@ -342,8 +373,10 @@ def _hybrid_period(cfg: ModelConfig, pp: Params, x, positions, *,
                    caches=None, cache_pos=None):
     """One period: sublayer 0 attention, 1..P-1 mamba; FFN after each mixer.
 
-    caches (decode): dict {kv_k, kv_v, conv (P-1,...), ssm (P-1,...)}.
-    Returns (x, aux, new_caches) — new_caches also returned at prefill.
+    caches (decode): dict {kv_k, kv_v, conv (P-1,...), ssm (P-1,...)}; the
+    kv slices are read only. Returns (x, aux, new_caches): at prefill the
+    whole k/v, at decode the new k/v row (the caller writes it) and the
+    updated mamba states.
     """
     _, P, moe_slots, dense_slots = _hybrid_layout(cfg)
     aux = jnp.float32(0.0)
@@ -417,7 +450,7 @@ def hybrid_init_cache(cfg: ModelConfig, batch: int, max_seq: int):
     n_periods, P, _, _ = _hybrid_layout(cfg)
     conv_s, ssm_s = mamba_state_shapes(cfg, batch)
     kv_len = min(max_seq, cfg.window) if cfg.window else max_seq
-    kv = jnp.zeros((n_periods, batch, kv_len, cfg.n_kv_heads, cfg.d_head),
+    kv = jnp.zeros((n_periods, batch, cfg.n_kv_heads, kv_len, cfg.d_head),
                    dtype)
     return {"kv_k": kv, "kv_v": kv,
             "conv": jnp.zeros((n_periods, P - 1) + conv_s, dtype),
@@ -430,10 +463,8 @@ def hybrid_prefill(params: Params, cfg: ModelConfig, tokens, max_seq: int):
     logits, _, caches = hybrid_forward(params, cfg, tokens, want_cache=True)
     kv_len = min(max_seq, cfg.window) if cfg.window else max_seq
     pad = kv_len - min(S, kv_len)
-    k = caches["kv_k"][:, :, -kv_len:]
-    v = caches["kv_v"][:, :, -kv_len:]
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    k, v = (_pad_seq(_head_major(caches[n])[:, :, :, -kv_len:], pad)
+            for n in ("kv_k", "kv_v"))
     cache = {"kv_k": k, "kv_v": v, "conv": caches["conv"],
              "ssm": caches["ssm"], "pos": jnp.int32(S)}
     return logits[:, -1], cache
@@ -443,25 +474,28 @@ def hybrid_decode(params: Params, cfg: ModelConfig, tokens, cache):
     B = tokens.shape[0]
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
     pos = cache["pos"]
-    kv_len = cache["kv_k"].shape[2]
+    kv_len = cache["kv_k"].shape[3]
     write_pos = jnp.minimum(pos, kv_len - 1)   # ring-ish clamp for window
     positions = jnp.broadcast_to(pos[None, None], (B, 1)).astype(jnp.int32)
 
     def body(carry, xs):
         x, aux = carry
-        pp, kv_k, kv_v, conv, ssm_state = xs
-        caches = {"kv_k": kv_k, "kv_v": kv_v, "conv": conv, "ssm": ssm_state}
+        pp, conv, ssm_state, period = xs
+        caches = {"kv_k": _layer(cache["kv_k"], period),
+                  "kv_v": _layer(cache["kv_v"], period),
+                  "conv": conv, "ssm": ssm_state}
         x, a, new = _hybrid_period(cfg, pp, x, positions, caches=caches,
                                    cache_pos=write_pos)
         return (x, aux + a), new
 
     (x, _), new = jax.lax.scan(
         body, (x, jnp.float32(0.0)),
-        (params["periods"], cache["kv_k"], cache["kv_v"], cache["conv"],
-         cache["ssm"]))
+        (params["periods"], cache["conv"], cache["ssm"],
+         jnp.arange(cache["kv_k"].shape[0])))
     x = norm(params["final_norm"], x, cfg.norm)
     logits = x @ params["head"]
-    new_cache = {"kv_k": new["kv_k"], "kv_v": new["kv_v"],
+    new_cache = {"kv_k": _write_rows(cache["kv_k"], new["kv_k"], write_pos),
+                 "kv_v": _write_rows(cache["kv_v"], new["kv_v"], write_pos),
                  "conv": new["conv"], "ssm": new["ssm"], "pos": pos + 1}
     return logits[:, -1], new_cache
 
@@ -558,7 +592,7 @@ def encdec_forward(params: Params, cfg: ModelConfig, frames, tokens,
 def encdec_init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                       dec_len: int = 448):
     dtype = jnp.dtype(cfg.dtype)
-    kv = jnp.zeros((cfg.n_layers, batch, dec_len, cfg.n_kv_heads, cfg.d_head),
+    kv = jnp.zeros((cfg.n_layers, batch, cfg.n_kv_heads, dec_len, cfg.d_head),
                    dtype)
     cross = jnp.zeros((cfg.n_layers, batch, max_seq, cfg.n_kv_heads,
                        cfg.d_head), dtype)
@@ -573,9 +607,7 @@ def encdec_prefill(params: Params, cfg: ModelConfig, frames, tokens,
                                     want_cache=True)
     (k, v), (ck, cv) = kvs
     S = tokens.shape[1]
-    pad = dec_len - S
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    k, v = (_pad_seq(_head_major(kv), dec_len - S) for kv in (k, v))
     cache = {"k": k, "v": v, "cross_k": ck, "cross_v": cv,
              "pos": jnp.int32(S)}
     return logits[:, -1], cache
@@ -591,23 +623,27 @@ def encdec_decode(params: Params, cfg: ModelConfig, tokens, cache):
 
     def body(carry, xs):
         x = carry
-        lp, ck, cv, xk, xv = xs
-        h, (nk, nv) = attention(lp["attn"], norm(lp["ln1"], x, cfg.norm), cfg,
-                                positions=positions, cache=(ck, cv),
-                                cache_pos=pos, use_rope=False)
+        lp, layer = xs
+        kv = (_layer(cache["k"], layer), _layer(cache["v"], layer))
+        h, rows = attention(lp["attn"], norm(lp["ln1"], x, cfg.norm), cfg,
+                            positions=positions, cache=kv, cache_pos=pos,
+                            use_rope=False)
         x = x + h
+        cross = (_layer(cache["cross_k"], layer),
+                 _layer(cache["cross_v"], layer))
         x = x + cross_attention(lp["cross"], norm(lp["ln_x"], x, cfg.norm),
-                                cfg, (xk, xv))
+                                cfg, cross)
         x = x + mlp(lp["ffn"], norm(lp["ln2"], x, cfg.norm), cfg)
-        return x, (nk, nv)
+        return x, rows
 
     x, (nks, nvs) = jax.lax.scan(
-        body, x, (params["dec_layers"], cache["k"], cache["v"],
-                  cache["cross_k"], cache["cross_v"]))
+        body, x, (params["dec_layers"], jnp.arange(cfg.n_layers)))
     x = norm(params["final_norm"], x, cfg.norm)
     logits = x @ params["embed"].T.astype(x.dtype)
-    new_cache = {"k": nks, "v": nvs, "cross_k": cache["cross_k"],
-                 "cross_v": cache["cross_v"], "pos": pos + 1}
+    new_cache = {"k": _write_rows(cache["k"], nks, pos),
+                 "v": _write_rows(cache["v"], nvs, pos),
+                 "cross_k": cache["cross_k"], "cross_v": cache["cross_v"],
+                 "pos": pos + 1}
     return logits[:, -1], new_cache
 
 
@@ -635,9 +671,9 @@ def decoder_init_cache_mixed(cfg: ModelConfig, batch: int, max_seq: int):
     is_global = _lg_layout(cfg)
     n_glob = int(is_global.sum())
     n_loc = cfg.n_layers - n_glob
-    glob = jnp.zeros((n_glob, batch, max_seq, cfg.n_kv_heads, cfg.d_head),
+    glob = jnp.zeros((n_glob, batch, cfg.n_kv_heads, max_seq, cfg.d_head),
                      dtype)
-    loc = jnp.zeros((n_loc, batch, cfg.window, cfg.n_kv_heads, cfg.d_head),
+    loc = jnp.zeros((n_loc, batch, cfg.n_kv_heads, cfg.window, cfg.d_head),
                     dtype)
     return {"k_global": glob, "v_global": glob, "k_local": loc,
             "v_local": loc, "pos": jnp.int32(0)}
@@ -679,7 +715,10 @@ def decoder_decode_mixed(params: Params, cfg: ModelConfig, tokens, cache):
     x = norm(params["final_norm"], x, cfg.norm)
     head = params.get("head")
     logits = x @ (head if head is not None else params["embed"].T.astype(x.dtype))
-    new_cache = {"k_global": jnp.stack(new_g_k), "v_global": jnp.stack(new_g_v),
-                 "k_local": jnp.stack(new_l_k), "v_local": jnp.stack(new_l_v),
-                 "pos": pos + 1}
+    new_cache = {
+        "k_global": _write_rows(cache["k_global"], jnp.stack(new_g_k), pos),
+        "v_global": _write_rows(cache["v_global"], jnp.stack(new_g_v), pos),
+        "k_local": _write_rows(cache["k_local"], jnp.stack(new_l_k), ring_pos),
+        "v_local": _write_rows(cache["v_local"], jnp.stack(new_l_v), ring_pos),
+        "pos": pos + 1}
     return logits[:, -1], new_cache

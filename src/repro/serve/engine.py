@@ -13,15 +13,19 @@ LM profiles advertise (see ``repro/env/profiles.py::lm_profile``):
 Two implementations share one public API:
 
 ``ServingEngine`` (the production path) is device-resident: every slot's KV
-cache lives in ONE stacked ``(slots, ...)`` pytree that stays on device and
-is donated through each step, and a decode step for ALL slots is ONE jitted
-dispatch (a vmap of the batch-1 decode over the slot axis — per-slot ``pos``
-cursors ride as a ``(slots,)`` leaf). Finished slots free-run (their lane
-keeps decoding; the host simply stops reading the lane) so no masking
-touches the KV leaves. Prompts are right-padded to power-of-two buckets and
-prefilled with a traced true-length, so prefill compiles once per bucket
-instead of once per distinct prompt length; prefill + slot insertion is one
-fused donated dispatch. Steady state performs ZERO recompiles — gated via
+cache lives in ONE stacked pytree that stays on device and is donated
+through each step, and a decode step for ALL slots is ONE jitted dispatch
+(a vmap of the batch-1 decode over the slot axis — per-slot ``pos`` cursors
+ride as a ``(slots,)`` leaf). Each leaf is slot-major: ``(slots, L, 1, KH,
+max_seq, D)`` for a head-major K or V. The step reads each layer's slice
+where it lies and never modifies it; its one write to the cache puts each
+slot's new K/V row at that slot's ``pos``, in place in the donated buffer,
+so the step copies no layer slice and no whole cache. Finished slots
+free-run (their lane keeps decoding; the host simply stops reading the
+lane, and an admission overwrites the whole slot). Prompts are right-padded
+to power-of-two buckets and prefilled with a traced true-length, so prefill
+compiles once per bucket instead of once per distinct prompt length;
+prefill + slot insertion is one fused donated dispatch. Steady state performs ZERO recompiles — gated via
 ``TRACE_COUNTS['serve_decode_step'/'serve_prefill']``.
 
 ``DictCacheEngine`` is the seed-era engine (per-slot ``Dict[int, cache]``,
@@ -152,10 +156,13 @@ class ServingEngine(_EngineBase):
     def __init__(self, model: Model, params, cfg: EngineConfig):
         super().__init__(model, params, cfg)
         # (slots, ...) stacked cache: each leaf of the batch-1 cache gains a
-        # leading slot axis; per-slot write cursors live in the ``pos`` leaf
-        self._cache = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[model.init_cache(1, cfg.max_seq) for _ in range(cfg.slots)])
+        # leading slot axis; per-slot write cursors live in the ``pos`` leaf.
+        # The vmap keeps that axis leading in and out, so nothing transposes
+        # the cache around the decode. Built in one program, so the device
+        # holds it once (stacking per-slot caches held it twice)
+        self._cache = jax.jit(lambda: jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (cfg.slots,) + x.shape),
+            model.init_cache(1, cfg.max_seq)))()
         self._last = jnp.zeros((cfg.slots,), jnp.int32)
         self._buckets = model.supports_padded_prefill
         slots = cfg.slots

@@ -48,8 +48,8 @@ def test_decode_attention_sweep(dtype, B, H, KH, S, D, bs, length, start):
     length = min(length, S)
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kc = jax.random.normal(ks[1], (B, S, KH, D), dtype)
-    vc = jax.random.normal(ks[2], (B, S, KH, D), dtype)
+    kc = jax.random.normal(ks[1], (B, KH, S, D), dtype)
+    vc = jax.random.normal(ks[2], (B, KH, S, D), dtype)
     out = decode_attention_pallas(q, kc, vc, jnp.int32(length),
                                   jnp.int32(start), block_s=bs,
                                   interpret=True)

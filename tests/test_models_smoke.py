@@ -59,6 +59,13 @@ def test_smoke_serve_path(arch, key):
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
 
 
+def _forward_logits(cfg, params, toks):
+    from repro.models import transformer as T
+    forward = {"ssm": T.ssm_forward, "hybrid": T.hybrid_forward}.get(
+        cfg.family, T.decoder_forward)
+    return forward(params, cfg, toks)[0]
+
+
 @pytest.mark.parametrize("arch", ["qwen3-32b", "mamba2-370m",
                                   "jamba-1.5-large-398b"])
 def test_decode_matches_teacher_forcing(arch, key):
@@ -69,13 +76,7 @@ def test_decode_matches_teacher_forcing(arch, key):
     B, S = 2, 16
     toks = jax.random.randint(key, (B, S + 1), 0, cfg.vocab, jnp.int32)
     # teacher-forced logits at position S (prediction after S+1 tokens)
-    from repro.models import transformer as T
-    if cfg.family == "ssm":
-        full, _, _ = T.ssm_forward(params, cfg, toks)
-    elif cfg.family == "hybrid":
-        full, _, _ = T.hybrid_forward(params, cfg, toks)
-    else:
-        full, _, _ = T.decoder_forward(params, cfg, toks)
+    full = _forward_logits(cfg, params, toks)
     want = full[:, S - 1]   # prediction for token at index S
     logits, cache = model.prefill(params, {"tokens": toks[:, :S]},
                                   max_seq=S + 4)
@@ -86,3 +87,27 @@ def test_decode_matches_teacher_forcing(arch, key):
     got2, _ = model.decode(params, toks[:, S:S + 1], cache)
     assert jnp.allclose(got2, want2, atol=5e-3, rtol=1e-2), (
         arch, float(jnp.max(jnp.abs(got2 - want2))))
+
+
+@pytest.mark.parametrize("arch,mixed", [
+    ("qwen3-32b", False), ("gemma3-1b", False), ("gemma3-1b", True),
+    ("mamba2-370m", False)])
+def test_decode_from_empty_cache_matches_teacher_forcing(arch, mixed, key):
+    """Token by token from an empty cache to its last row (gemma3's ring
+    caches wrap): each step reads the rows the earlier steps wrote, so its
+    logits match teacher forcing at that position. (MoE families drop
+    tokens past an expert's capacity in the forward pass, so their logits
+    differ from decode's on their own.)"""
+    cfg = dataclasses.replace(ARCHS[arch].smoke(), dtype="float32",
+                              mixed_cache=mixed)
+    model = build(cfg)
+    params = model.init(key)
+    B, S = 2, 20
+    toks = jax.random.randint(key, (B, S), 0, cfg.vocab, jnp.int32)
+    full = _forward_logits(cfg, params, toks)
+    decode = jax.jit(model.decode)
+    cache = model.init_cache(B, S)
+    for i in range(S):
+        got, cache = decode(params, toks[:, i:i + 1], cache)
+        assert jnp.allclose(got, full[:, i], atol=5e-3, rtol=1e-2), (
+            arch, i, float(jnp.max(jnp.abs(got - full[:, i]))))

@@ -4,12 +4,15 @@ Invariants under test (ISSUE 10):
  * requests complete, chip budget gates admission, context truncates (seed);
  * admission never exceeds the chip-scaled token budget; slots free on
    completion;
- * dict-cache and stacked engines emit identical token streams on a seeded
-   run (the stacked path is an optimization, not a semantic change);
+ * dict-cache and stacked engines emit identical token streams on seeded
+   runs (the stacked path is an optimization, not a semantic change):
+   mixed lengths, lanes far apart, a lane writing at max_seq - 1, a freed
+   lane decoding while another slot admits, and the hybrid and ssm
+   families;
  * bucketed prefill traces once per power-of-two bucket and the decode step
    traces once, total — zero steady-state recompiles;
  * the opt-in Pallas decode-attention path matches the reference stream in
-   interpret mode;
+   interpret mode, on the same runs;
  * ``ServedLMService`` telemetry is measured — its profile's analytic
    ``tp_max`` is never called.
 """
@@ -119,22 +122,62 @@ def test_slots_free_on_completion():
     assert engine.queue == []
 
 
-def test_dict_and_stacked_streams_identical():
-    """Seeded run, mixed prompt lengths: the stacked engine must reproduce
-    the dict engine's token streams bit-for-bit (float32, same params)."""
-    lengths = [7, 13, 19, 26]
+# Request plans: (slots, [(step it is submitted at, prompt length, new
+# tokens)]). ``mixed``: the seed run. ``spread``: lanes at widely different
+# positions. ``full``: a lane writes its last row at max_seq - 1 (prompt 32
+# + 32 decode steps, max_seq 64). ``refill``: slot 2 frees and keeps
+# decoding while a late request is admitted into slot 0.
+PLANS = {
+    "mixed": (3, [(0, n, 5) for n in [7, 13, 19, 26] * 2]),
+    "spread": (3, [(0, 30, 24), (0, 2, 24), (0, 16, 24)]),
+    "full": (2, [(0, 32, 33), (0, 5, 10)]),
+    "refill": (3, [(0, 6, 3), (0, 20, 14), (0, 9, 5), (6, 11, 6)]),
+}
+
+
+def _serve(engine, cfg, plan, seed=2):
+    """Run ``plan`` on ``engine``, each request submitted at its step; the
+    token streams by request id."""
+    rng = np.random.default_rng(seed)
+    reqs = [(at, Request(rid, rng.integers(0, cfg.vocab, n, dtype=np.int64)
+                         .astype(np.int32), max_new_tokens=m))
+            for rid, (at, n, m) in enumerate(plan)]
+    for step in range(200):
+        for at, req in reqs:
+            if at == step:
+                engine.submit(req)
+        engine.step()
+        if len(engine.completed) == len(reqs):
+            break
+    assert len(engine.completed) == len(reqs)
+    return {r.rid: list(r.generated) for r in engine.completed}
+
+
+@pytest.mark.parametrize("plan", list(PLANS))
+def test_dict_and_stacked_streams_identical(plan):
+    """Seeded run: the stacked engine must reproduce the dict engine's token
+    streams bit-for-bit (float32, same params)."""
+    slots, reqs = PLANS[plan]
     streams = {}
     for cls in (DictCacheEngine, ServingEngine):
-        engine, cfg = make_engine(slots=3, chips=4.0, cls=cls)
-        for req in _requests(cfg, 8, lengths, max_new=5, seed=2):
-            engine.submit(req)
-        for _ in range(100):
-            engine.step()
-            if len(engine.completed) == 8:
-                break
-        assert len(engine.completed) == 8
-        streams[cls.__name__] = {r.rid: list(r.generated)
-                                 for r in engine.completed}
+        engine, cfg = make_engine(slots=slots, chips=4.0, cls=cls)
+        streams[cls.__name__] = _serve(engine, cfg, reqs)
+    assert streams["DictCacheEngine"] == streams["ServingEngine"]
+
+
+@pytest.mark.parametrize("arch", ["jamba-1.5-large-398b", "mamba2-370m"])
+def test_recurrent_family_streams_identical(arch):
+    """The hybrid and ssm families through the stacked engine's slot
+    stacking: the dict engine's exact streams."""
+    cfg = get(arch).smoke()
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    slots, reqs = PLANS["refill"]
+    streams = {}
+    for cls in (DictCacheEngine, ServingEngine):
+        engine = cls(model, params, EngineConfig(slots=slots, max_seq=64,
+                                                 context=32, chips=4.0))
+        streams[cls.__name__] = _serve(engine, cfg, reqs)
     assert streams["DictCacheEngine"] == streams["ServingEngine"]
 
 
@@ -159,21 +202,15 @@ def test_prefill_traces_once_per_bucket():
     assert TRACE_COUNTS["serve_decode_step"] - before_d == 1
 
 
-def test_pallas_interpret_stream_parity():
+@pytest.mark.parametrize("plan", list(PLANS))
+def test_pallas_interpret_stream_parity(plan):
     """The opt-in Pallas decode-attention route under the vmapped stacked
     step must emit the reference engine's exact token stream."""
-    lengths = [9, 14]
+    slots, reqs = PLANS[plan]
     streams = {}
     for impl in ("reference", "pallas_interpret"):
-        engine, cfg = make_engine(slots=2, attn_impl=impl)
-        for req in _requests(cfg, 3, lengths, max_new=4, seed=4):
-            engine.submit(req)
-        for _ in range(40):
-            engine.step()
-            if len(engine.completed) == 3:
-                break
-        assert len(engine.completed) == 3
-        streams[impl] = {r.rid: list(r.generated) for r in engine.completed}
+        engine, cfg = make_engine(slots=slots, attn_impl=impl)
+        streams[impl] = _serve(engine, cfg, reqs)
     assert streams["reference"] == streams["pallas_interpret"]
 
 
